@@ -23,7 +23,7 @@ import os
 
 import jax
 import jax.numpy as jnp
-from jax.sharding import PartitionSpec as P
+from jax.sharding import AbstractMesh, PartitionSpec as P
 
 from repro import tuner
 from repro.core import ledger
@@ -33,7 +33,6 @@ from repro.core.hw import (MiB, CXLPoolConfig, ICIConfig,
 from repro.core.topology import Level, Topology
 
 AXES = ("pod", "node", "gpu")
-SHAPE = ((("pod", 2), ("node", 2), ("gpu", 2)))
 PLAN_ARTIFACT = os.environ.get("BENCH_TOPO_PLAN",
                                "bench-topology-plan.json")
 
@@ -42,19 +41,6 @@ TOPOLOGY = Topology(levels=(
     Level("node", "cxl", pool=CXLPoolConfig(device_bw=18e9)),
     Level("gpu", "ici", ici=ICIConfig(link_bw=45e9)),
 ))
-
-
-def _abstract_mesh():
-    """AbstractMesh across jax versions (no devices needed to trace)."""
-    from jax.sharding import AbstractMesh
-    try:
-        return AbstractMesh(SHAPE)
-    except TypeError:
-        pass
-    try:   # newer signature: (axis_sizes, axis_names)
-        return AbstractMesh(tuple(s for _, s in SHAPE), AXES)
-    except TypeError:
-        return AbstractMesh({a: s for a, s in SHAPE})
 
 
 def _trace(mesh, fn, nbytes: int) -> dict:
@@ -91,7 +77,7 @@ def run(emit, smoke: bool = False) -> None:
         emit(f"topology_level_{lv.axis}_cxl_fraction", frac,
              f"{lv.fabric} fabric, fp {lv.fingerprint()}")
 
-    mesh = _abstract_mesh()
+    mesh = AbstractMesh((2, 2, 2), AXES)   # no devices needed to trace
     comm = Communicator(backend="auto", plan=plan, topology=TOPOLOGY)
     size = (16 if smoke else 64) * MiB
 

@@ -40,9 +40,8 @@ def main() -> None:
     # online: one flag's worth of setup - the plan carries the topology
     plan = tuner.load_plan(path, topology=topo)
     comm = Communicator(backend="auto", plan=plan)
-    mesh = jax.sharding.AbstractMesh((("pod", 2), ("node", 2),
-                                      ("gpu", 2)))
     axes = ("pod", "node", "gpu")
+    mesh = jax.sharding.AbstractMesh((2, 2, 2), axes)
 
     ledger.reset()
     jax.eval_shape(jax.shard_map(

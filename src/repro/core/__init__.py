@@ -1,31 +1,3 @@
 # The paper's primary contribution — implement the SYSTEM here
 # (scheduler, optimizer, data path, serving loop, etc.) in the
 # host framework. Add sibling subpackages for substrates.
-
-# Compat: the codebase targets the modern `jax.shard_map(..., check_vma=)`
-# entry point; older jax (<= 0.4.x) only ships
-# `jax.experimental.shard_map.shard_map(..., check_rep=)`.  Install an
-# equivalent alias so every call site works on both.
-import jax as _jax
-
-if not hasattr(_jax, "shard_map"):
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    def _compat_shard_map(f, *, mesh, in_specs, out_specs, check_vma=True,
-                          **kw):
-        return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs, check_rep=check_vma, **kw)
-
-    _jax.shard_map = _compat_shard_map
-
-if not hasattr(_jax.lax, "axis_size"):
-    from jax.lax import psum as _psum
-
-    def _axis_size(axis_name):
-        # psum of 1 over the axis folds to the (static) axis size at
-        # trace time - the old-jax spelling of lax.axis_size.
-        return _psum(1, axis_name)
-
-    _jax.lax.axis_size = _axis_size
-
-del _jax

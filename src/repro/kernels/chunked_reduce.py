@@ -29,7 +29,7 @@ def _kernel(x_ref, o_ref):
 
 @functools.partial(jax.jit, static_argnames=("tile", "interpret"))
 def chunked_reduce(x: jnp.ndarray, tile: int = DEFAULT_TILE,
-                   interpret: bool = True) -> jnp.ndarray:
+                   *, interpret: bool) -> jnp.ndarray:
     """Sum ``x`` (n_src, length) over sources, tiled along length."""
     n_src, length = x.shape
     pad = (-length) % tile

@@ -77,7 +77,7 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
 def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
                     causal: bool = True, window=None,
                     block_q: int = BLOCK_Q, block_k: int = BLOCK_K,
-                    interpret: bool = True) -> jnp.ndarray:
+                    *, interpret: bool) -> jnp.ndarray:
     """q/k/v: (BH, L, D) with L divisible by the block sizes."""
     bh, lq, d = q.shape
     lk = k.shape[1]

@@ -19,16 +19,19 @@ kernels here close that gap (ROADMAP item 4):
   gathered operand shard-by-shard: the grid's innermost axis walks the
   rank-major shard stack, so Pallas's pipelined block fetch brings
   shard k+1 into VMEM while shard k is on the MXU (the
-  ``flash_attention`` kv-innermost pattern).  ``fused_dense`` wraps it
+  ``flash_attention`` kv-innermost pattern).  The output columns are
+  tiled too, so a weight block is one shard's rows by ``COL_TILE``
+  columns whatever the layer's width.  ``fused_dense`` wraps it
   with a custom VJP so it can sit on the differentiated FSDP path
   (``models.layers.dense``); the backward pass is plain-jnp reference
   math.
 
 Pure-jnp oracles live in ``kernels.ref``; ``kernels.ops`` carries the
-interpret-defaulting public wrappers.  Accumulation is f32 throughout,
-matching the unfused reference composition op-for-op so fp32 inputs
-reproduce it bitwise where the schedule permits (the elementwise
-epilogues; the matmul differs only in f32 summation order).
+public wrappers and decides ``interpret`` for the backend.  Accumulation
+is f32 throughout, matching the unfused reference composition
+op-for-op so fp32 inputs reproduce it bitwise where the schedule
+permits (the elementwise epilogues; the matmul differs only in f32
+summation order).
 """
 from __future__ import annotations
 
@@ -41,6 +44,10 @@ from jax.experimental.pallas import tpu as pltpu
 
 ROW_TILE = 128          # token rows per grid step (rs+rmsnorm, matmul)
 SEG_TILE = 2048         # flat elements per grid step (rs+adamw)
+# output columns per grid step (matmul): a (Ks, COL_TILE) f32 weight
+# block, double-buffered, stays inside the default scoped VMEM for the
+# widest llama3.2-1b shard (Ks = 8192 / 4) - 2 x 4 MiB
+COL_TILE = 512
 
 
 # --------------------------------------------------------------------- #
@@ -58,8 +65,8 @@ def _rs_rmsnorm_kernel(x_ref, s_ref, o_ref, *, eps: float):
 
 @functools.partial(jax.jit, static_argnames=("eps", "rows", "interpret"))
 def reduce_scatter_rmsnorm(shards: jnp.ndarray, scale: jnp.ndarray,
-                           eps: float = 1e-5, rows: int = ROW_TILE,
-                           interpret: bool = True) -> jnp.ndarray:
+                           eps: float = 1e-5, rows: int = ROW_TILE, *,
+                           interpret: bool) -> jnp.ndarray:
     """``shards``: (n_src, T, D) peer partials -> (T, D) normalized sum."""
     n_src, t, d = shards.shape
     r = min(rows, t)
@@ -109,8 +116,8 @@ def reduce_scatter_adamw(shards: jnp.ndarray, p: jnp.ndarray,
                          m: jnp.ndarray, v: jnp.ndarray, lr, bc1, bc2,
                          b1: float = 0.9, b2: float = 0.95,
                          eps: float = 1e-8, weight_decay: float = 0.0,
-                         tile: int = SEG_TILE,
-                         interpret: bool = True) -> tuple:
+                         tile: int = SEG_TILE, *,
+                         interpret: bool) -> tuple:
     """``shards``: (n_src, L) grad partials; ``p``/``m``/``v``: (L,)
     param and f32 moments; ``lr``/``bc1``/``bc2`` traced scalars (the
     schedule LR and bias corrections ``1 - b^step``).  Returns
@@ -152,7 +159,7 @@ def reduce_scatter_adamw(shards: jnp.ndarray, p: jnp.ndarray,
 # --------------------------------------------------------------------- #
 
 def _ag_matmul_kernel(x_ref, w_ref, o_ref, acc_scr, *, nk: int):
-    k = pl.program_id(1)
+    k = pl.program_id(2)
 
     @pl.when(k == 0)
     def _init():
@@ -172,12 +179,13 @@ def _ag_matmul_kernel(x_ref, w_ref, o_ref, acc_scr, *, nk: int):
 
 @functools.partial(jax.jit, static_argnames=("rows", "interpret"))
 def all_gather_matmul(x: jnp.ndarray, w_shards: jnp.ndarray,
-                      rows: int = ROW_TILE,
-                      interpret: bool = True) -> jnp.ndarray:
+                      rows: int = ROW_TILE, *,
+                      interpret: bool) -> jnp.ndarray:
     """``x``: (T, n*Ks) activations; ``w_shards``: (n, Ks, N) rank-major
     gathered weight shards.  Returns ``x @ concat(w_shards)`` without
     ever materializing the concatenated weight: the contraction streams
-    the shard stack through VMEM, one shard per (sequential) grid step.
+    the shard stack through VMEM, one shard per (sequential) grid step,
+    for each (``rows``, ``COL_TILE``) output tile.
     """
     n, ks, nout = w_shards.shape
     t, kdim = x.shape
@@ -186,20 +194,24 @@ def all_gather_matmul(x: jnp.ndarray, w_shards: jnp.ndarray,
             f"contraction mismatch: x has {kdim} columns, shards give "
             f"{n}x{ks}")
     r = min(rows, t)
-    pad = (-t) % r
-    if pad:
-        x = jnp.pad(x, ((0, pad), (0, 0)))
+    c = min(COL_TILE, nout)
+    pad_t, pad_n = (-t) % r, (-nout) % c
+    if pad_t:
+        x = jnp.pad(x, ((0, pad_t), (0, 0)))
+    if pad_n:
+        w_shards = jnp.pad(w_shards, ((0, 0), (0, 0), (0, pad_n)))
     out = pl.pallas_call(
         functools.partial(_ag_matmul_kernel, nk=n),
-        grid=((t + pad) // r, n),
-        in_specs=[pl.BlockSpec((r, ks), lambda i, k: (i, k)),
-                  pl.BlockSpec((1, ks, nout), lambda i, k: (k, 0, 0))],
-        out_specs=pl.BlockSpec((r, nout), lambda i, k: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((t + pad, nout), x.dtype),
-        scratch_shapes=[pltpu.VMEM((r, nout), jnp.float32)],
+        grid=((t + pad_t) // r, (nout + pad_n) // c, n),
+        in_specs=[pl.BlockSpec((r, ks), lambda i, j, k: (i, k)),
+                  pl.BlockSpec((1, ks, c), lambda i, j, k: (k, 0, j))],
+        out_specs=pl.BlockSpec((r, c), lambda i, j, k: (i, j)),
+        out_shape=jax.ShapeDtypeStruct((t + pad_t, nout + pad_n),
+                                       x.dtype),
+        scratch_shapes=[pltpu.VMEM((r, c), jnp.float32)],
         interpret=interpret,
     )(x, w_shards)
-    return out[:t]
+    return out[:t, :nout]
 
 
 # --------------------------------------------------------------------- #
@@ -208,7 +220,7 @@ def all_gather_matmul(x: jnp.ndarray, w_shards: jnp.ndarray,
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(2,))
 def fused_dense(x: jnp.ndarray, w_shards: jnp.ndarray,
-                interpret: bool = True) -> jnp.ndarray:
+                interpret: bool) -> jnp.ndarray:
     """``x @ concat(w_shards)`` over the last dim of ``x`` (leading dims
     are batch), forward via :func:`all_gather_matmul`.  Differentiable:
     the VJP is the plain-jnp reference matmul transpose (the fusion win

@@ -27,7 +27,7 @@ def _kernel(x_ref, s_ref, o_ref, *, eps: float):
 
 @functools.partial(jax.jit, static_argnames=("eps", "rows", "interpret"))
 def rms_norm(x: jnp.ndarray, scale: jnp.ndarray, eps: float = 1e-5,
-             rows: int = ROW_TILE, interpret: bool = True) -> jnp.ndarray:
+             rows: int = ROW_TILE, *, interpret: bool) -> jnp.ndarray:
     t, d = x.shape
     r = min(rows, t)
     pad = (-t) % r

@@ -60,7 +60,7 @@ def _kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, dres_ref, y_ref, h_scr,
 def ssm_scan(x: jnp.ndarray, dt: jnp.ndarray, a: jnp.ndarray,
              bs: jnp.ndarray, cs: jnp.ndarray, d_res: jnp.ndarray,
              block_d: int = BLOCK_D, block_l: int = BLOCK_L,
-             interpret: bool = True) -> jnp.ndarray:
+             *, interpret: bool) -> jnp.ndarray:
     """See module docstring for shapes."""
     b, l, d = x.shape
     n = a.shape[1]

@@ -32,6 +32,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs import ARCH_IDS, get_config
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import model
 from repro.serving import (Request, SamplingParams, ServeConfig,
                            ServeEngine)
@@ -209,6 +210,7 @@ def main() -> None:
                          "repro.obs")
     xla.add_argument(ap)
     args = ap.parse_args()
+    enable_compile_cache()
     if args.online_retune and not args.plan:
         ap.error("--online-retune requires --plan")
     if args.trace and args.online_retune:

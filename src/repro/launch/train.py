@@ -53,16 +53,15 @@ from repro.launch import xla
 xla.apply_overlap_preset()   # --xla-overlap: must precede the jax import
 
 import jax
-import jax.numpy as jnp
 
 from repro.configs import ARCH_IDS, get_config
 from repro.data.pipeline import SyntheticTokens
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import dp_axes, make_production_mesh
-from repro.models import model
-from repro.optim import adamw_init
 from repro.training import checkpoint
-from repro.training.train_loop import (TrainConfig,
-                                       make_sharded_train_step)
+from repro.training.train_loop import (TrainConfig, init_sharded_state,
+                                       make_sharded_train_step,
+                                       named_shardings)
 
 
 def main() -> None:
@@ -195,6 +194,7 @@ def main() -> None:
                          "plan cells at refresh (requires "
                          "--online-retune)")
     args = ap.parse_args()
+    enable_compile_cache()
     if args.online_retune and args.backend != "auto":
         ap.error("--online-retune requires --backend auto")
     if (args.ewma_decay or args.explore_eps) and not args.online_retune:
@@ -306,9 +306,9 @@ def main() -> None:
         step, pspecs, bspecs, pc = make_sharded_train_step(
             cfg, tcfg, mesh, dp_axis=dp_axes(mesh))
         tp = mesh.shape["model"]
-    params = model.init_params(jax.random.key(0), cfg, tp=tp,
-                               dtype=jnp.float32)
-    opt = adamw_init(params)
+    params, opt = init_sharded_state(cfg, mesh, pspecs,
+                                     jax.random.key(0), tp=tp)
+    batch_sh = named_shardings(mesh, bspecs)
     data = iter(SyntheticTokens(cfg, batch=args.batch, seq=args.seq))
 
     online = None
@@ -384,7 +384,7 @@ def main() -> None:
         if fault_plan is not None:
             for ev in fault_plan.begin_step(i, emulator=emu):
                 print(f"step {i:5d} fault injected: {ev.describe()}")
-        batch = {k: jnp.asarray(v) for k, v in batch.items()}
+        batch = jax.device_put(batch, {k: batch_sh[k] for k in batch})
         ts = time.perf_counter()
         step_timings = None
         with (obs_sess.step_span(i) if obs_sess is not None

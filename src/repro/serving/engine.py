@@ -153,6 +153,9 @@ class ServeEngine:
                          "replays": 0, "prefix_hits": 0,
                          "prefix_hit_tokens": 0, "prefix_publishes": 0,
                          "decode_steps": 0, "prefills": 0}
+        # request id -> host copy of its logits from the latest decode
+        # step that advanced it (for checks against a reference)
+        self.last_logits: dict = {}
 
         self._prefill = jax.jit(
             lambda p, b: model.prefill(p, b, cfg, pc, scfg.max_seq,
@@ -539,6 +542,7 @@ class ServeEngine:
             jnp.asarray(pos), jnp.asarray(active))
         self.counters["decode_steps"] += 1
         rows = np.asarray(logits)[:, 0]
+        self.last_logits = {st.req.id: rows[st.slot] for st in stepping}
         for st in stepping:
             st.pos += 1
             if st.forced:

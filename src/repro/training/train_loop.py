@@ -197,6 +197,28 @@ def make_sharded_train_step(cfg: ModelConfig, tcfg: TrainConfig, mesh,
     return step_fn, pspecs, bspecs, pc
 
 
+def named_shardings(mesh, specs):
+    """PartitionSpec pytree -> the matching NamedSharding pytree."""
+    return jax.tree.map(lambda s: NamedSharding(mesh, s), specs,
+                        is_leaf=lambda s: isinstance(s, P))
+
+
+def init_sharded_state(cfg: ModelConfig, mesh, pspecs, key,
+                       tp: int = 1) -> tuple:
+    """fp32 params and AdamW state for the sharded step, built by one
+    jitted init whose ``out_shardings`` are the step's own specs: every
+    device materializes only its shards, so no device ever holds the
+    whole model (an eager init would stage it all on device 0 and leave
+    the first step to reshard it)."""
+    p_sh = named_shardings(mesh, pspecs)
+    o_sh = AdamWState(step=NamedSharding(mesh, P()), mu=p_sh, nu=p_sh)
+
+    def init(k):
+        params = model.init_params(k, cfg, tp=tp, dtype=jnp.float32)
+        return params, adamw_init(params)
+    return jax.jit(init, out_shardings=(p_sh, o_sh))(key)
+
+
 def train(cfg: ModelConfig, tcfg: TrainConfig, data_iter, steps: int,
           params=None, key=None, log_every: int = 10,
           log_fn=print) -> tuple:

@@ -16,7 +16,7 @@ import numpy as np
 import pytest
 
 from repro.kernels import ops, ref
-from repro.kernels.fused_collectives import ROW_TILE, SEG_TILE
+from repro.kernels.fused_collectives import COL_TILE, ROW_TILE, SEG_TILE
 
 RNG = np.random.default_rng(0)
 
@@ -114,6 +114,25 @@ def test_ag_matmul_matches_ref(n, t, ks, nout):
     assert got.shape == (t, nout)
     # same f32 accumulation, different summation order (per-shard
     # partials vs one dot): tight allclose, not bitwise
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=1e-5, atol=1e-5)
+
+
+@pytest.mark.parametrize("nout", [
+    2 * COL_TILE,             # two column tiles
+    2 * COL_TILE + 76,        # ragged width: padded last column tile
+])
+def test_ag_matmul_column_tiles(nout):
+    """Output columns tile independently (each tile accumulates its own
+    shard stream); a width that is no multiple of the tile is padded
+    and sliced back."""
+    n, t, ks = 4, 64, 16
+    rng = np.random.default_rng(nout)   # leaves the module stream alone
+    x = jnp.asarray(rng.normal(size=(t, n * ks)), jnp.float32)
+    w = jnp.asarray(rng.normal(size=(n, ks, nout)), jnp.float32)
+    got = ops.all_gather_matmul(x, w)
+    want = ref.all_gather_matmul_ref(x, w)
+    assert got.shape == (t, nout)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=1e-5, atol=1e-5)
 

@@ -26,6 +26,19 @@ def test_split_chunks_scalar_and_single():
     assert len(mc._split_chunks(jnp.arange(8.0), 1)) == 1
 
 
+def test_lane_rows_only_for_whole_tiles():
+    """Flat buffers run the schedules as (rows, 128) when every rank's
+    share is whole (8, 128) tiles; anything else stays as it is."""
+    x = jnp.arange(2 * 1024.0)
+    rows = mc._lane_rows(x, 2)
+    assert rows.shape == (16, 128)
+    np.testing.assert_array_equal(np.asarray(rows).reshape(-1),
+                                  np.asarray(x))
+    assert mc._lane_rows(x, 4) is None            # 512 per rank
+    assert mc._lane_rows(jnp.arange(1000.0)) is None
+    assert mc._lane_rows(x.reshape(16, 128)) is None   # already 2-D
+
+
 def test_ring_perm():
     assert mc._ring_perm(4) == [(0, 1), (1, 2), (2, 3), (3, 0)]
     assert mc._ring_perm(4, shift=2) == [(0, 2), (1, 3), (2, 0), (3, 1)]

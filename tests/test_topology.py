@@ -214,14 +214,14 @@ def test_warn_uncovered_mesh_axes():
     import jax
 
     from repro.core.topology import warn_uncovered
-    mesh = jax.sharding.AbstractMesh((("data", 2), ("model", 4)))
+    mesh = jax.sharding.AbstractMesh((2, 4), ("data", "model"))
     wrong = parse_topology("node:cxl,gpu:ici")
     with pytest.warns(UserWarning, match="data.*model"):
         assert warn_uncovered(wrong, mesh) == ("data", "model")
     right = parse_topology("data:cxl,model:ici")
     assert warn_uncovered(right, mesh) == ()
     # size-1 axes need no level (nothing to communicate over)
-    mesh1 = jax.sharding.AbstractMesh((("pod", 1), ("data", 2)))
+    mesh1 = jax.sharding.AbstractMesh((1, 2), ("pod", "data"))
     assert warn_uncovered(parse_topology("data:cxl"), mesh1) == ()
 
 
