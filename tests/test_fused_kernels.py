@@ -105,6 +105,8 @@ def test_rs_adamw_padding_leaves_tail_untouched():
     (2, ROW_TILE, 32, 48),    # one row tile
     (4, 200, 16, 64),         # odd rows: padded grid
     (8, 64, 8, 128),          # many shards
+    (4, 64, 16, 2 * COL_TILE),        # two output column tiles
+    (4, 64, 16, 2 * COL_TILE + 76),   # ragged width: padded last tile
 ])
 def test_ag_matmul_matches_ref(n, t, ks, nout):
     x = jnp.asarray(RNG.normal(size=(t, n * ks)), jnp.float32)
@@ -114,25 +116,6 @@ def test_ag_matmul_matches_ref(n, t, ks, nout):
     assert got.shape == (t, nout)
     # same f32 accumulation, different summation order (per-shard
     # partials vs one dot): tight allclose, not bitwise
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
-                               rtol=1e-5, atol=1e-5)
-
-
-@pytest.mark.parametrize("nout", [
-    2 * COL_TILE,             # two column tiles
-    2 * COL_TILE + 76,        # ragged width: padded last column tile
-])
-def test_ag_matmul_column_tiles(nout):
-    """Output columns tile independently (each tile accumulates its own
-    shard stream); a width that is no multiple of the tile is padded
-    and sliced back."""
-    n, t, ks = 4, 64, 16
-    rng = np.random.default_rng(nout)   # leaves the module stream alone
-    x = jnp.asarray(rng.normal(size=(t, n * ks)), jnp.float32)
-    w = jnp.asarray(rng.normal(size=(n, ks, nout)), jnp.float32)
-    got = ops.all_gather_matmul(x, w)
-    want = ref.all_gather_matmul_ref(x, w)
-    assert got.shape == (t, nout)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=1e-5, atol=1e-5)
 
