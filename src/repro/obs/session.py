@@ -8,7 +8,8 @@ flags every launcher exposes (``--metrics-out``, ``--trace-out``):
   measured plan-cell regret) and, at ``finalize``, the trace-time
   ledger gauges;
 * the flight-recorder tracer (enabled only when ``--trace-out`` is
-  given - tracing off means zero hooks registered, zero overhead);
+  given - tracing off means zero hooks registered and nothing
+  recorded; spans stay profiler annotations);
 * a ``HealthMonitor`` whose degradation flags trigger an immediate
   flight-recorder dump, so the trace that led up to the anomaly is on
   disk even if the run dies next step.
@@ -68,9 +69,7 @@ class ObsSession:
         return contextlib.nullcontext()
 
     def span(self, name: str, **tags):
-        if self.tracer is not None:
-            return self.tracer.span(name, **tags)
-        return contextlib.nullcontext()
+        return obs_trace.span(name, **tags)
 
     # -- event stream -----------------------------------------------------
 

@@ -22,6 +22,13 @@ the trace that *led up to* the anomaly survives even if the run dies.
 complete events), loadable in Perfetto / ``chrome://tracing``: steps
 and phases nest on one track by timestamp containment, measured
 collectives render on a second track.
+
+``span(name, **stats)`` is what program code opens, inside a
+``step(name, index)``.  Every span and step is also a
+``jax.profiler.TraceAnnotation`` (a span's ``stats`` as its event
+stats), whether or not the tracer is enabled, so a profiler capture
+holds the same spans on the device trace's clock.  With no profiler
+running an annotation costs a few microseconds (docs/OBSERVABILITY.md).
 """
 from __future__ import annotations
 
@@ -29,6 +36,8 @@ import collections
 import contextlib
 import json
 import time
+
+import jax
 
 from repro.core import ledger
 
@@ -59,36 +68,46 @@ class Tracer:
         return time.perf_counter() - self._t0
 
     @contextlib.contextmanager
-    def step(self, index: int):
-        """One training/serving step: the ring-buffer unit."""
-        if not self.enabled:
-            yield
-            return
-        prev_events, prev_index = self._events, self._step_index
-        self._events, self._step_index = [], int(index)
-        t0 = self._now()
-        try:
-            yield
-        finally:
-            dur = self._now() - t0
-            events = self._events
-            events.insert(0, ("X", "step", f"step {index}", t0, dur,
-                              (("step", int(index)),)))
-            self._steps.append((int(index), events))
-            self._events, self._step_index = prev_events, prev_index
+    def step(self, index: int, name: "str | None" = None):
+        """One training/serving step: the ring-buffer unit, named
+        ``name`` (``step <index>`` by default).  Always a
+        ``jax.profiler.TraceAnnotation`` of that name, like ``span``."""
+        name = name or f"step {index}"
+        with jax.profiler.TraceAnnotation(name):
+            if not self.enabled:
+                yield
+                return
+            prev_events, prev_index = self._events, self._step_index
+            self._events, self._step_index = [], int(index)
+            t0 = self._now()
+            try:
+                yield
+            finally:
+                dur = self._now() - t0
+                events = self._events
+                events.insert(0, ("X", "step", name, t0, dur,
+                                  (("step", int(index)),)))
+                self._steps.append((int(index), events))
+                self._events, self._step_index = prev_events, prev_index
 
     @contextlib.contextmanager
     def span(self, name: str, kind: str = "phase", **tags):
-        """A named sub-region of the current step (phase, retune, ...)."""
-        if not self.enabled:
-            yield
-            return
-        t0 = self._now()
-        try:
-            yield
-        finally:
-            self._events.append(("X", kind, name, t0, self._now() - t0,
-                                 tuple(tags.items())))
+        """A named sub-region of the current step (phase, retune, ...).
+
+        Always a ``jax.profiler.TraceAnnotation`` with ``tags`` as its
+        stats, so a profiler capture holds it on the device trace's
+        clock; also a flight-recorder span while the tracer is
+        enabled."""
+        with jax.profiler.TraceAnnotation(name, **tags):
+            if not self.enabled:
+                yield
+                return
+            t0 = self._now()
+            try:
+                yield
+            finally:
+                self._events.append(("X", kind, name, t0,
+                                     self._now() - t0, tuple(tags.items())))
 
     def instant(self, name: str, kind: str = "mark", **tags) -> None:
         if self.enabled:
@@ -179,6 +198,20 @@ _TRACER = Tracer()
 
 def get_tracer() -> Tracer:
     return _TRACER
+
+
+def span(name: str, **stats):
+    """A span on the global tracer: what program code opens, so a
+    ``jax.profiler`` capture and ``--trace-out`` hold the same spans.
+    Open it inside a ``step``: spans outside one collect in the
+    recorder's preamble, which only ``enable_tracing`` empties."""
+    return _TRACER.span(name, **stats)
+
+
+def step(name: str, index: int):
+    """A step on the global tracer (see ``Tracer.step``): a profiler
+    annotation, and the flight recorder's ring unit while enabled."""
+    return _TRACER.step(index, name=name)
 
 
 def enable_tracing(capacity_steps: int = DEFAULT_CAPACITY) -> Tracer:

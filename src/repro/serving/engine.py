@@ -47,6 +47,7 @@ from repro.core import ledger
 from repro.models import model
 from repro.models.config import ModelConfig
 from repro.models.pcontext import ParallelContext, UNSHARDED
+from repro.obs.trace import span, step as trace_step
 from repro.serving import kvcache
 from repro.serving.scheduler import (FINISHED, RUNNING, Request,
                                      RequestState, SamplingParams,
@@ -157,10 +158,11 @@ class ServeEngine:
         # step that advanced it (for checks against a reference)
         self.last_logits: dict = {}
 
-        self._prefill = jax.jit(
-            lambda p, b: model.prefill(p, b, cfg, pc, scfg.max_seq,
-                                       cache_dtype=cd,
-                                       window=scfg.window))
+        def prefill_impl(p, b):
+            return model.prefill(p, b, cfg, pc, scfg.max_seq,
+                                 cache_dtype=cd, window=scfg.window)
+
+        self._prefill = jax.jit(prefill_impl)
 
         def step_impl(p, c, tok, pos, active):
             logits, nc = model.decode_step(p, c, tok, pos, cfg, pc,
@@ -236,15 +238,8 @@ class ServeEngine:
         (evicting to the pool when HBM runs out), run one jitted
         decode step over every running slot, sample/advance each
         request.  Returns True while work remains."""
-        span = self.obs.span("serve_step") if self.obs is not None \
-            else None
-        if span is not None:
-            span.__enter__()
-        try:
+        with trace_step("serve.step", self.counters["decode_steps"]):
             self._do_step()
-        finally:
-            if span is not None:
-                span.__exit__(None, None, None)
         self._export_metrics()
         return not self.sched.idle
 
@@ -319,7 +314,8 @@ class ServeEngine:
                                  backend, 1, "kv_tier")
         slot = st.slot
         if backend == "pool":
-            img = self.layout.extract_slot(self.caches, slot, st.pos)
+            with span("serve.kv.extract"):
+                img = self.layout.extract_slot(self.caches, slot, st.pos)
             if not self.pool.put(("evict", self._uid, st.req.id), img):
                 self._diag(f"pool budget full: eviction of "
                            f"{st.req.id!r} falls back to recompute")
@@ -356,26 +352,37 @@ class ServeEngine:
         self.sched.finish(st)
         self.counters["finished"] += 1
 
-    def _prefill_request(self, st: RequestState) -> None:
-        """Materialize a fresh prompt: full prefill into the slot via
-        the canonical byte image, then sample the first token."""
-        b = {"tokens": jnp.asarray(
-            np.asarray(st.req.tokens, np.int32)[None])}
-        if st.req.extras is not None:
-            for k, v in st.req.extras.items():
-                b[k] = jnp.asarray(np.asarray(v)[None])
-        logits, c1 = self._prefill(self.params, b)
+    def _prefill_into_slot(self, st: RequestState):
+        """Prefill ``st``'s prompt at batch 1 and copy its cache into
+        the slot through the canonical byte image; returns the logits
+        of the last prompt position."""
+        with span("serve.prefill"):
+            b = {"tokens": jnp.asarray(
+                np.asarray(st.req.tokens, np.int32)[None])}
+            if st.req.extras is not None:
+                for k, v in st.req.extras.items():
+                    b[k] = jnp.asarray(np.asarray(v)[None])
+            logits, c1 = jax.block_until_ready(
+                self._prefill(self.params, b))
         self.counters["prefills"] += 1
         st.n_prefix = self._n_prefix
         st.pos = self._prompt_ntok(st)
-        lay1 = self._lay1
-        img = lay1.extract_slot(c1, 0, st.pos)
-        self.caches = self.layout.insert_slot(self.caches, st.slot,
-                                              st.pos, img)
+        with span("serve.kv.extract"):
+            img = self._lay1.extract_slot(c1, 0, st.pos)
+        with span("serve.kv.insert"):
+            self.caches = self.layout.insert_slot(self.caches, st.slot,
+                                                  st.pos, img)
+        return logits
+
+    def _prefill_request(self, st: RequestState) -> None:
+        """Materialize a fresh prompt: full prefill into the slot, then
+        sample the first token."""
+        logits = self._prefill_into_slot(st)
         if self._share:
             self._publish_prefix(st)
-        tok = self._sample_one(np.asarray(logits)[0, -1],
-                               st.req.sampling, 0)
+        with span("serve.sample"):
+            tok = self._sample_one(np.asarray(logits)[0, -1],
+                                   st.req.sampling, 0)
         st.generated.append(tok)
         st.last_token = tok
 
@@ -397,8 +404,9 @@ class ServeEngine:
             key = ("kvblk", h)
             if key in self.pool:
                 continue
-            img = self.layout.extract_token_range(
-                self.caches, st.slot, i * bt, (i + 1) * bt)
+            with span("serve.kv.extract"):
+                img = self.layout.extract_token_range(
+                    self.caches, st.slot, i * bt, (i + 1) * bt)
             if not self.pool.put(key, img):
                 break               # pool full of pinned entries
             self.counters["prefix_publishes"] += 1
@@ -433,8 +441,9 @@ class ServeEngine:
             for key in keys:
                 self.pool.release(key)
         for i, img in enumerate(imgs):
-            self.caches = self.layout.insert_token_range(
-                self.caches, st.slot, i * bt, (i + 1) * bt, img)
+            with span("serve.kv.insert"):
+                self.caches = self.layout.insert_token_range(
+                    self.caches, st.slot, i * bt, (i + 1) * bt, img)
         prefix = run * bt
         st.pos = prefix
         st.forced = tuple(st.req.tokens[prefix:])
@@ -453,60 +462,43 @@ class ServeEngine:
         return True
 
     def _admit(self, st: RequestState, slot: int) -> None:
-        if st.preemptions:
-            key = ("evict", self._uid, st.req.id)
-            img = self.pool.get(key)
-            if img is not None:
-                # Bitwise restore of the evicted image (blocks were
-                # reserved at admission).
-                self.caches = self.layout.insert_slot(
-                    self.caches, slot, st.pos, img)
-                self.pool.remove(key)
-                self.counters["restores"] += 1
+        with span("serve.admit", req=st.req.id, tokens=len(st.req.tokens)):
+            if st.preemptions:
+                key = ("evict", self._uid, st.req.id)
+                img = self.pool.get(key)
+                if img is not None:
+                    # Bitwise restore of the evicted image (blocks were
+                    # reserved at admission).
+                    with span("serve.kv.insert"):
+                        self.caches = self.layout.insert_slot(
+                            self.caches, slot, st.pos, img)
+                    self.pool.remove(key)
+                    self.counters["restores"] += 1
+                    return
+                self._replay(st)
                 return
-            # Recompute path: re-prefill the prompt, then teacher-
-            # force the tokens already sampled (minus the last, which
-            # is the next step's input).  The sample stream is index-
-            # keyed, so the continuation is unchanged.
-            self._replay(st)
-            return
-        if self._try_prefix_restore(st):
-            return
-        self._prefill_request(st)
-        if st.done:
-            self._finish(st)
+            if self._try_prefix_restore(st):
+                return
+            self._prefill_request(st)
+            if st.done:
+                self._finish(st)
 
     def _replay(self, st: RequestState) -> None:
-        done_tokens = list(st.generated)
-        st.pos = 0
-        st.forced = ()
+        """Recompute path: re-prefill the prompt, then teacher-force the
+        tokens already sampled (minus the last, which is the next
+        step's input).  The sample stream is index-keyed, so the
+        continuation is unchanged."""
         # Re-size the admission reservation (made at the preempted
         # pos) down to the prompt; forced steps grow it back.
         self.blocks.free(st.req.id)
         self.blocks.alloc(st.req.id, self._prompt_ntok(st),
                           self._hashes(st))
-        self._prefill_request_replay(st, done_tokens)
-        self.counters["replays"] += 1
-
-    def _prefill_request_replay(self, st: RequestState,
-                                done_tokens: list) -> None:
-        b = {"tokens": jnp.asarray(
-            np.asarray(st.req.tokens, np.int32)[None])}
-        if st.req.extras is not None:
-            for k, v in st.req.extras.items():
-                b[k] = jnp.asarray(np.asarray(v)[None])
-        _logits, c1 = self._prefill(self.params, b)
-        self.counters["prefills"] += 1
-        st.pos = self._prompt_ntok(st)
-        img = self._lay1.extract_slot(c1, 0, st.pos)
-        self.caches = self.layout.insert_slot(self.caches, st.slot,
-                                              st.pos, img)
-        st.generated = done_tokens
-        # Feed back everything but the last sampled token; sampling
-        # must not rerun when the forced queue drains.
-        st.forced = tuple(done_tokens[:-1])
+        self._prefill_into_slot(st)
+        # Sampling must not rerun when the forced queue drains.
+        st.forced = tuple(st.generated[:-1])
         self._sample_after[st.req.id] = False
-        st.last_token = done_tokens[-1]
+        st.last_token = st.generated[-1]
+        self.counters["replays"] += 1
 
     def _do_step(self) -> None:
         for adm in self.sched.admissions(self._reserve):
@@ -537,23 +529,25 @@ class ServeEngine:
             tok[st.slot, 0] = feed
             pos[st.slot] = st.pos
             active[st.slot] = True
-        logits, self.caches = self._decode(
-            self.params, self.caches, jnp.asarray(tok),
-            jnp.asarray(pos), jnp.asarray(active))
+        with span("serve.decode"):
+            logits, self.caches = self._decode(
+                self.params, self.caches, jnp.asarray(tok),
+                jnp.asarray(pos), jnp.asarray(active))
+            rows = np.asarray(logits)[:, 0]
         self.counters["decode_steps"] += 1
-        rows = np.asarray(logits)[:, 0]
         self.last_logits = {st.req.id: rows[st.slot] for st in stepping}
-        for st in stepping:
-            st.pos += 1
-            if st.forced:
-                st.forced = st.forced[1:]
+        with span("serve.sample"):
+            for st in stepping:
+                st.pos += 1
                 if st.forced:
-                    continue
-                if not self._sample_after.pop(st.req.id, True):
-                    continue        # replay rejoin: last_token is set
-            tokv = self._sample_one(rows[st.slot], st.req.sampling,
-                                    len(st.generated))
-            st.generated.append(tokv)
-            st.last_token = tokv
-            if st.done:
-                self._finish(st)
+                    st.forced = st.forced[1:]
+                    if st.forced:
+                        continue
+                    if not self._sample_after.pop(st.req.id, True):
+                        continue    # replay rejoin: last_token is set
+                tokv = self._sample_one(rows[st.slot], st.req.sampling,
+                                        len(st.generated))
+                st.generated.append(tokv)
+                st.last_token = tokv
+                if st.done:
+                    self._finish(st)

@@ -21,6 +21,7 @@ from repro.obs import (HealthMonitor, MetricsRegistry, ObsSession,
                        trace_timings)
 from repro.obs import metrics as obs_metrics
 from repro.obs import profile as obs_profile
+from repro.obs import trace as obs_trace
 from repro.obs.trace import Tracer
 from repro.tuner import costmodel, runtime
 
@@ -92,6 +93,67 @@ def test_tracer_span_nesting_and_containment():
         assert parent["ts"] <= child["ts"]
         assert (child["ts"] + child["dur"]
                 <= parent["ts"] + parent["dur"] + 1e-6)
+
+
+def _serve_a_little(new_tokens: int = 3):
+    """Two requests through a tiny serving engine, to completion."""
+    import jax
+    import jax.numpy as jnp
+
+    from repro.configs import get_config
+    from repro.models import model
+    from repro.serving import Request, ServeConfig, ServeEngine
+    cfg = get_config("llama3.2-1b", smoke=True)
+    params = model.init_params(jax.random.key(0), cfg, tp=1,
+                               dtype=jnp.float32)
+    eng = ServeEngine(cfg, params, ServeConfig(max_seq=32,
+                                               decode_slots=2))
+    toks = np.random.default_rng(1).integers(0, cfg.vocab_size, (2, 8))
+    for b in range(2):
+        eng.submit(Request(id=f"q{b}", tokens=toks[b],
+                           max_new_tokens=new_tokens))
+    while eng.step():
+        pass
+
+
+def test_span_reaches_profiler_and_flight_recorder(profiled):
+    tr = enable_tracing(capacity_steps=4)
+
+    def run():
+        with obs_trace.span("serve.x", req="r1", ntok=5):
+            _serve_a_little()
+    spans = profiled(run)
+    assert spans[0][0] == "serve.x"
+    assert spans[0][3] == {"req": "r1", "ntok": 5}
+    recorded = [e for e in tr.dump()["traceEvents"] if e.get("ph") == "X"]
+    assert recorded[-1]["name"] == "serve.x"
+    assert recorded[-1]["args"] == {"req": "r1", "ntok": 5}
+    assert sorted(e["name"] for e in recorded) == sorted(
+        s[0] for s in spans)
+
+
+def test_engine_steps_keep_the_flight_recorder_bounded():
+    tr = enable_tracing(capacity_steps=3)
+    _serve_a_little(new_tokens=12)
+    disable_tracing()
+    # every engine span lies inside a ``serve.step``, the ring's unit,
+    # so the last 3 of about 12 steps are all that is kept
+    assert len(tr.steps_retained()) == 3
+    assert not tr._events
+    kept = [e["name"] for e in tr.dump()["traceEvents"]
+            if e.get("ph") == "X"]
+    assert kept.count("serve.step") == 3
+    assert set(kept) == {"serve.step", "serve.decode", "serve.sample"}
+
+
+def test_spans_with_tracing_off_leave_the_recorder_empty(profiled):
+    tr = enable_tracing()
+    disable_tracing()
+    spans = profiled(_serve_a_little)
+    assert {"serve.step", "serve.admit", "serve.decode"} <= {
+        s[0] for s in spans}
+    assert not tr.dump()["traceEvents"][3:]
+    assert tr.steps_retained() == []
 
 
 def test_tracer_ledger_hook_bridges_collectives(tmp_path):

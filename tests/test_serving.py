@@ -317,3 +317,61 @@ def test_prefix_store_doorbell_and_refcount_protocol():
     pool.release("a")
     pool.remove("a")
     assert "a" not in pool
+
+
+# -- engine spans on the profiler's clock ----------------------------------
+
+
+def _within(outer, spans, name=None):
+    return [s for s in spans if s is not outer and (name is None
+            or s[0] == name) and outer[1] <= s[1] and s[2] <= outer[2]]
+
+
+def test_engine_spans_nest_under_the_profiler(profiled):
+    cfg, eng = _engine(decode_slots=2)
+    toks = RNG.integers(0, cfg.vocab_size, (3, 8))
+    for b in range(3):
+        eng.submit(Request(id=f"p{b}", tokens=toks[b], max_new_tokens=4))
+
+    def drain():
+        while eng.step():
+            pass
+    spans = profiled(drain)
+    steps = [s for s in spans if s[0] == "serve.step"]
+    decoded = 0
+    for st in steps:
+        dec = _within(st, spans, "serve.decode")
+        assert len(dec) <= 1
+        decoded += len(dec)
+    assert decoded == eng.counters["decode_steps"] > 0
+    admits = [s for s in spans if s[0] == "serve.admit"]
+    assert sorted(a[3]["req"] for a in admits) == ["p0", "p1", "p2"]
+    for a in admits:
+        assert a[3]["tokens"] == 8
+        assert {s[0] for s in _within(a, spans)} == {
+            "serve.prefill", "serve.kv.extract", "serve.kv.insert",
+            "serve.sample"}
+        assert any(_within(st, [a]) for st in steps)
+
+
+@pytest.mark.parametrize("placement,inner", [
+    ("pool", {"serve.kv.insert"}),
+    ("recompute", {"serve.prefill", "serve.kv.extract", "serve.kv.insert"})])
+def test_eviction_spans(profiled, placement, inner):
+    cfg, eng = _engine(kv_placement=placement, **_TIGHT)
+    toks = RNG.integers(0, cfg.vocab_size, (3, 8))
+    spans = profiled(lambda: eng.generate({"tokens": jnp.asarray(toks)},
+                                          6))
+    admits = [s for s in spans if s[0] == "serve.admit"]
+    # a preempted request's second admission restores or replays it
+    again = [a for i, a in enumerate(admits)
+             if a[3]["req"] in {b[3]["req"] for b in admits[:i]}]
+    assert again and all({s[0] for s in _within(a, spans)} == inner
+                         for a in again)
+    # an eviction's extract happens in a step, outside any admission
+    evicted = [s for s in spans if s[0] == "serve.kv.extract"
+               and not any(_within(a, [s]) for a in admits)]
+    assert bool(evicted) == (placement == "pool")
+    steps = [s for s in spans if s[0] == "serve.step"]
+    for s in evicted:
+        assert any(_within(st, [s]) for st in steps)
