@@ -177,23 +177,26 @@ def _kv_to_cache(k: jnp.ndarray, v: jnp.ndarray, cfg: ModelConfig,
 
 
 def row_decode(p: Params, h: jnp.ndarray, kind: str, cache: dict,
-               pos: jnp.ndarray, cfg: ModelConfig, pc: ParallelContext,
+               at: tuple, pos: jnp.ndarray, active: jnp.ndarray,
+               cfg: ModelConfig, pc: ParallelContext,
                window: Optional[int] = None):
-    """Single-token decode for one row.  ``cache`` layouts:
-    attention: {'k','v'} (+{'ck','cv'} cross KV); mamba: {'conv','ssm'}.
-    Returns (h, new_cache)."""
+    """Single-token decode for one row.  ``cache`` holds the whole
+    leaves the caller carries and ``at`` indexes this row in them
+    (``()`` for an unstacked row); layouts: attention {'k','v'}
+    (+{'ck','cv'} cross KV, read only); mamba {'conv','ssm'}.  ``pos``
+    and ``active`` are (B,): slots that are not ``active`` keep their
+    state bit for bit.  Returns (h, new_cache)."""
     if kind in ("a", "e"):
         attn_in = layers.rms_norm(h, p["norm1"], cfg.norm_eps)
-        kv_write = pos % window if window is not None else pos
         out, ck, cv = layers.decode_attention(
-            p["attn"], attn_in, cache["k"], cache["v"], pos, cfg, pc,
-            window=window, kv_write_pos=kv_write)
+            p["attn"], attn_in, cache["k"], cache["v"], at, pos, active,
+            cfg, pc, window=window)
         h = h + out
         new_cache = dict(cache, k=ck, v=cv)
         if "xattn" in p and "ck" in cache:
             x_in = layers.rms_norm(h, p["norm_x"], cfg.norm_eps)
-            h = h + _cross_decode(p["xattn"], x_in, cache["ck"],
-                                  cache["cv"], cfg, pc)
+            h = h + _cross_decode(p["xattn"], x_in, cache["ck"][at],
+                                  cache["cv"][at], cfg, pc)
         ff_in = layers.rms_norm(h, p["norm2"], cfg.norm_eps)
         if kind == "a":
             h = h + layers.ffn_forward(p["ffn"], ff_in, pc)
@@ -205,15 +208,24 @@ def row_decode(p: Params, h: jnp.ndarray, kind: str, cache: dict,
                                      capacity=cap)
             h = h + out
         return h, new_cache
+    # Recurrent state is small and rewritten whole every step: select
+    # per slot, then write this row back.
+    state = {k: v[at] for k, v in cache.items()}
     m_in = layers.rms_norm(h, p["norm1"], cfg.norm_eps)
     if kind == "1":
         out, (conv, st) = ssm.mamba1_decode(
-            p["mamba"], m_in, (cache["conv"], cache["ssm"]), cfg, pc)
-        return h + out, dict(cache, conv=conv, ssm=st)
-    out, (cx, cbc, st) = ssm.mamba2_decode(
-        p["mamba"], m_in,
-        (cache["conv"], cache["conv_bc"], cache["ssm"]), cfg, pc)
-    return h + out, dict(cache, conv=cx, conv_bc=cbc, ssm=st)
+            p["mamba"], m_in, (state["conv"], state["ssm"]), cfg, pc)
+        new = dict(conv=conv, ssm=st)
+    else:
+        out, (cx, cbc, st) = ssm.mamba2_decode(
+            p["mamba"], m_in,
+            (state["conv"], state["conv_bc"], state["ssm"]), cfg, pc)
+        new = dict(conv=cx, conv_bc=cbc, ssm=st)
+
+    def put(leaf, old, nv):
+        keep = active.reshape((-1,) + (1,) * (nv.ndim - 1))
+        return leaf.at[at].set(jnp.where(keep, nv.astype(leaf.dtype), old))
+    return h + out, {k: put(cache[k], state[k], new[k]) for k in cache}
 
 
 def _cross_decode(p: Params, x: jnp.ndarray, ck: jnp.ndarray,

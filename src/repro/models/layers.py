@@ -157,16 +157,6 @@ def select_kv(k: jnp.ndarray, v: jnp.ndarray, d: "AttnDims",
     return jnp.take(k, kv_idx, axis=2), jnp.take(v, kv_idx, axis=2)
 
 
-def select_kv_global(k: jnp.ndarray, v: jnp.ndarray, hq_full: int,
-                     cfg: ModelConfig):
-    """Same mapping for the decode path where all q heads are gathered:
-    hq_full may include padding; k/v hold all kv heads."""
-    n_kv = k.shape[2]
-    g = max(1, cfg.n_heads // max(cfg.n_kv_heads, 1))
-    kv_idx = jnp.clip(jnp.arange(hq_full) // g, 0, n_kv - 1)
-    return jnp.take(k, kv_idx, axis=2), jnp.take(v, kv_idx, axis=2)
-
-
 FLASH_THRESHOLD = 1024  # sequences this long use blocked attention
 
 
@@ -229,32 +219,37 @@ def attention_forward(params: Params, x: jnp.ndarray, cfg: ModelConfig,
 # -- decode path --------------------------------------------------------- #
 
 def decode_attention(params: Params, x: jnp.ndarray, cache_k, cache_v,
-                     pos: jnp.ndarray, cfg: ModelConfig,
-                     pc: ParallelContext,
-                     window: Optional[int] = None,
-                     kv_write_pos: Optional[jnp.ndarray] = None):
-    """One-token decode with a sequence-sharded KV cache.
+                     at: tuple, pos: jnp.ndarray, active: jnp.ndarray,
+                     cfg: ModelConfig, pc: ParallelContext,
+                     window: Optional[int] = None):
+    """One-token decode with a sequence-sharded KV cache, written in
+    place.
 
-    x: (B, 1, d_model).  cache_{k,v}: (B, S_local, n_kv, hd) - the local
-    slice of a cache whose *global* sequence length is S_local * tp (tp
-    sharded) or S_local (unsharded).  ``pos``: the global position being
-    written - a scalar int32 (whole batch at one position, the static
-    batch-synchronous path) or a ``(B,)`` int32 vector (per-slot
-    positions, the continuous-batching engine where every decode slot
-    carries its own request).  For a ring-buffer window cache the
-    caller passes ``kv_write_pos`` = pos % window (same rank as
-    ``pos``).
+    x: (B, 1, d_model).  cache_{k,v}: (..., B, S_local, n_kv, hd) - the
+    whole cache leaf the caller carries (a scan group's stack of
+    layers); ``at`` indexes this row's (B, S_local, n_kv, hd) slice,
+    ``()`` when the leaf is one row.  S_local is the local slice of a
+    cache whose *global* sequence length is S_local * tp (tp sharded)
+    or S_local (unsharded).  ``pos``: (B,) int32, the global position
+    each slot writes; ``active``: (B,) bool.  A ring-buffer window
+    cache writes at pos % window.
+
+    Each slot's new K/V row is scattered at (``at``, b, pos[b]); an
+    inactive slot, or a shard that does not own the position, gets an
+    out-of-range index that the scatter drops, so the rest of the
+    cache is never rewritten.  The row is then read once for
+    attention, query heads grouped onto their KV head as
+    (B, n_kv, heads per KV head, hd).
 
     Returns (attn_out (B,1,d_model), new_cache_k, new_cache_v).
     """
     d = attn_dims(cfg, pc.tp)
     b = x.shape[0]
-    s_local = cache_k.shape[1]
+    s_local = cache_k.shape[-3]
     tp_idx = pc.tp_index()
-    vec = jnp.ndim(pos) > 0   # per-slot positions (trace-time static)
+    rope_pos = pos.reshape(b, 1)
 
     q = dense(x, params["wq"]).reshape(b, 1, d.n_q, d.head_dim)
-    rope_pos = pos.reshape(b, 1) if vec else pos[None].reshape(1,)
     q = apply_rope(q, rope_pos, cfg.rope_theta)
     # KV for the new token: computed on every shard (redundant but tiny),
     # using the *full* kv-head projection when kv is replicated; when kv
@@ -267,45 +262,35 @@ def decode_attention(params: Params, x: jnp.ndarray, cache_k, cache_v,
         # (B,1,n_kv_local,hd) -> all heads: gather over tp along head dim
         k_new = _gather_heads(k_new, pc)
         v_new = _gather_heads(v_new, pc)
-    n_kv_full = k_new.shape[2]
 
-    write = kv_write_pos if kv_write_pos is not None else pos
-    # Which shard owns this cache slot?
-    owner = (write // s_local) if pc.tp > 1 else jnp.int32(0)
-    local_off = write % s_local
-    sel = (owner == tp_idx) | (pc.tp == 1)
-    if vec:
-        # Per-slot write offsets: a dynamic_update_slice cannot take a
-        # batch of offsets, so the write is a one-hot select over the
-        # local sequence axis (O(S) lanes, exact - only the hit slot of
-        # a selected batch row changes).
-        hit = jnp.arange(s_local)[None, :] == local_off[:, None]
-        sel_b = jnp.broadcast_to(sel, (b,))  # scalar True when tp == 1
-        mask4 = (hit & sel_b[:, None])[..., None, None]     # (B,S,1,1)
-        cache_k = jnp.where(mask4, k_new.astype(cache_k.dtype), cache_k)
-        cache_v = jnp.where(mask4, v_new.astype(cache_v.dtype), cache_v)
-    else:
-        upd_k = lax.dynamic_update_slice(
-            cache_k, k_new.astype(cache_k.dtype),
-            (0, local_off.astype(jnp.int32), 0, 0))
-        upd_v = lax.dynamic_update_slice(
-            cache_v, v_new.astype(cache_v.dtype),
-            (0, local_off.astype(jnp.int32), 0, 0))
-        cache_k = jnp.where(sel, upd_k, cache_k)
-        cache_v = jnp.where(sel, upd_v, cache_v)
+    write = pos % window if window is not None else pos
+    keep = active
+    if pc.tp > 1:
+        keep = keep & (write // s_local == tp_idx)   # owning shard only
+    row = jnp.where(keep, write % s_local, s_local)  # s_local: dropped
+    idx = (*at, jnp.arange(b), row)
+    cache_k = cache_k.at[idx].set(k_new[:, 0].astype(cache_k.dtype),
+                                  mode="drop")
+    cache_v = cache_v.at[idx].set(v_new[:, 0].astype(cache_v.dtype),
+                                  mode="drop")
+    ck, cv = cache_k[at], cache_v[at]                # (B,S_local,n_kv,hd)
 
-    # Partial attention over the local sequence slice, all q heads.
+    # Partial attention over the local sequence slice, all q heads,
+    # each group of g query heads against its KV head as it lies in the
+    # cache.  Padded q heads (hq_full > n_heads) are cut here and come
+    # back as zeros below: their wo rows are zero.
     q_full = _gather_heads(q, pc) if pc.tp > 1 else q   # (B,1,Hq_full,hd)
     hq_full = q_full.shape[2]
-    kk, vv = select_kv_global(cache_k, cache_v, hq_full, cfg)
-    logits = jnp.einsum("bqhd,bkhd->bhqk", q_full, kk,
+    n_kv = ck.shape[2]
+    g = cfg.n_heads // n_kv
+    qg = q_full[:, 0, :cfg.n_heads].reshape(b, n_kv, g, d.head_dim)
+    logits = jnp.einsum("bkgd,bskd->bkgs", qg, ck,
                         preferred_element_type=jnp.float32)
     logits = logits / math.sqrt(d.head_dim)
     # mask invalid cache slots: global slot index of local slot j
     base = tp_idx * s_local if pc.tp > 1 else 0
-    slot_pos = base + jnp.arange(s_local)
-    sp = slot_pos[None, None, None, :]
-    pv = pos.reshape(b, 1, 1, 1) if vec else pos
+    sp = (base + jnp.arange(s_local))[None, None, None, :]
+    pv = pos.reshape(b, 1, 1, 1)
     if window is not None:
         # ring buffer: before the buffer wraps (pos < window) only slots
         # <= pos hold data; afterwards every slot is live.
@@ -313,16 +298,19 @@ def decode_attention(params: Params, x: jnp.ndarray, cache_k, cache_v,
     else:
         valid = sp <= pv
     logits = jnp.where(valid, logits, -jnp.inf)
-    m = jnp.max(logits, axis=-1)                          # (B,H,1)
+    m = jnp.max(logits, axis=-1)                          # (B,K,G)
     m_glob = pc.tp_psum_max(m)
     p = jnp.exp(logits - m_glob[..., None])
     p = jnp.where(valid, p, 0.0)
-    l_part = jnp.sum(p, axis=-1)                          # (B,H,1)
-    o_part = jnp.einsum("bhqk,bkhd->bqhd", p.astype(vv.dtype), vv)
+    l_part = jnp.sum(p, axis=-1)                          # (B,K,G)
+    o_part = jnp.einsum("bkgs,bskd->bkgd", p.astype(cv.dtype), cv)
     l_glob = pc.tp_all_reduce(l_part)
     o_glob = pc.tp_all_reduce(o_part.astype(jnp.float32))
-    out_full = o_glob / jnp.maximum(
-        l_glob, 1e-20).transpose(0, 2, 1)[..., None]      # (B,1,H,hd)
+    out_full = (o_glob / jnp.maximum(l_glob, 1e-20)[..., None]).reshape(
+        b, 1, cfg.n_heads, d.head_dim)
+    if hq_full > cfg.n_heads:
+        out_full = jnp.pad(out_full, ((0, 0), (0, 0),
+                                      (0, hq_full - cfg.n_heads), (0, 0)))
     # Row-parallel output projection: my shard's q-head slice only.
     if pc.tp > 1:
         my = pc.tp_index()

@@ -296,31 +296,43 @@ def init_cache(cfg: ModelConfig, pc: ParallelContext, batch: int,
 
 def decode_step(params: Params, caches: list, tokens: jnp.ndarray,
                 pos: jnp.ndarray, cfg: ModelConfig, pc: ParallelContext,
-                window: Optional[int] = None):
+                window: Optional[int] = None,
+                active: Optional[jnp.ndarray] = None):
     """One decode step.  tokens: (B, 1) int32; pos: the global position
     being decoded - scalar int32 (whole batch in lockstep) or (B,)
-    int32 (per-slot positions for the continuous-batching engine; see
-    ``layers.decode_attention``).  Returns (logits (B, 1, V_padded),
-    new_caches)."""
+    int32 (per-slot positions for the continuous-batching engine).
+    ``active``: (B,) bool, default all; an inactive slot's cache is
+    left bit for bit as it was.  Each scan group carries its cache
+    leaves through the layer loop and every row writes only its new
+    positions into them (``layers.decode_attention``), so a caller
+    that donates ``caches`` gets the step's cache in the same buffers.
+    Returns (logits (B, 1, V_padded), new_caches)."""
+    b = tokens.shape[0]
+    pos = jnp.broadcast_to(pos, (b,)).astype(jnp.int32)
+    if active is None:
+        active = jnp.ones((b,), bool)
     h = layers.embed_tokens(params["embed"], tokens, cfg, pc)
     groups = blocks.scan_groups(cfg)
     new_caches = []
     for gi, g in enumerate(groups):
-        def body(carry, pc_pair):
-            p, cache = pc_pair
-            out, nc = blocks.row_decode(p, carry, g.kind, cache, pos,
-                                        cfg, pc, window=window)
-            return out, nc
+        def row(h, p, cache, at, kind=g.kind):
+            return blocks.row_decode(p, h, kind, cache, at, pos, active,
+                                     cfg, pc, window=window)
         if g.shared:
             gc = []
             for cache in caches[gi]:
-                h, nc = body(h, (params["shared_a"], cache))
+                h, nc = row(h, params["shared_a"], cache, ())
                 gc.append(nc)
             new_caches.append(gc)
         else:
+            def body(carry, xs):
+                h, cache = carry
+                p, i = xs
+                return row(h, p, cache, (i,)), None
             with ledger.scale(g.count):
-                h, nc = jax.lax.scan(body, h,
-                                     (params[f"g{gi}"], caches[gi]))
+                (h, nc), _ = jax.lax.scan(
+                    body, (h, caches[gi]),
+                    (params[f"g{gi}"], jnp.arange(g.count)))
             new_caches.append(nc)
     h = layers.rms_norm(h, params["final_norm"], cfg.norm_eps)
     logits_local = layers.lm_logits(params["embed"], h, cfg, pc)

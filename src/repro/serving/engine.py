@@ -165,10 +165,14 @@ class ServeEngine:
         self._prefill = jax.jit(prefill_impl)
 
         def step_impl(p, c, tok, pos, active):
-            logits, nc = model.decode_step(p, c, tok, pos, cfg, pc,
-                                           window=scfg.window)
-            return logits, self.layout.where_slots(active, nc, c)
+            return model.decode_step(p, c, tok, pos, cfg, pc,
+                                     window=scfg.window, active=active)
 
+        # The cache is not donated: a wrapper of the step (the
+        # benchmark's fault injection) may hand back the cache it
+        # passed in, and a donated buffer is gone after the call.  So
+        # the step copies the cache once on entry and writes only the
+        # new positions into that copy.
         self._decode = jax.jit(step_impl)
 
     # -- diagnostics / metrics --------------------------------------------

@@ -256,18 +256,6 @@ class CacheLayout:
             out.append(leaf.at[tuple(idx)].set(0))
         return self.treedef.unflatten(out)
 
-    def where_slots(self, active, new, old):
-        """jit-safe per-slot select: keep ``new`` where ``active`` else
-        ``old`` (discards the step's writes to inactive slots)."""
-        new_leaves = jax.tree.leaves(new)
-        old_leaves = jax.tree.leaves(old)
-        out = []
-        for ln, lo, sp in zip(new_leaves, old_leaves, self.leaves):
-            shape = [1] * ln.ndim
-            shape[sp.batch_axis] = -1
-            out.append(jnp.where(active.reshape(shape), ln, lo))
-        return self.treedef.unflatten(out)
-
 
 # -- HBM block accounting --------------------------------------------------
 

@@ -3,6 +3,9 @@ continuous-batching lifecycle (preempt / evict-to-pool / restore /
 replay), paged block accounting with shared prefixes, the pooled
 prefix cache across engines, and the ``generate()`` compat wrapper's
 bitwise equivalence to the old batch API."""
+import dataclasses
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -53,6 +56,63 @@ def test_ssm_engine_generates():
         RNG.integers(0, cfg.vocab_size, (2, 8)))}
     out = eng.generate(prompts, max_new_tokens=4)
     assert out.shape == (2, 4)
+
+
+@pytest.mark.parametrize("donated", [False, True],
+                         ids=["engine", "donated"])
+def test_decode_step_writes_the_cache_in_place_and_never_expands_heads(
+        donated):
+    """The decode program builds no whole new cache: the only values of
+    the full stacked-cache shape are the scatters that write the new
+    rows - no select, no one-hot, no dynamic_update_slice of a whole
+    row - and, where the cache is not donated (the engine's step), one
+    copy of each leaf at entry.  Given its cache donated, the step
+    returns every leaf in the input's buffer (``input_output_alias``).
+    No value takes the head-expanded (slots, seq, query heads,
+    head_dim) shape."""
+    cfg = dataclasses.replace(get_config("llama3.2-1b", smoke=True),
+                              d_model=224, n_heads=14, n_kv_heads=2)
+    params = model.init_params(KEY, cfg, tp=1, dtype=jnp.float32)
+    b, s = 3, 40
+    if donated:
+        caches = model.init_cache(cfg, UNSHARDED, b, s,
+                                  cache_dtype=jnp.float32)
+        step = jax.jit(lambda p, c, t, q, a: model.decode_step(
+            p, c, t, q, cfg, UNSHARDED, active=a), donate_argnums=(1,))
+    else:
+        eng = ServeEngine(cfg, params,
+                          ServeConfig(max_seq=s, decode_slots=b))
+        caches, step = eng.caches, eng._decode
+    lowered = step.lower(params, caches, jnp.zeros((b, 1), jnp.int32),
+                         jnp.zeros((b,), jnp.int32), jnp.zeros((b,), bool))
+    hlo = lowered.compile().as_text()
+    leaves = jax.tree.leaves(caches)
+    assert len(leaves) == 2                 # the layer-stacked k and v
+    full = "f32[" + ",".join(map(str, leaves[0].shape)) + "]"
+    made = re.findall(re.escape(full) + r"\S* ([\w-]+)\((.*)", hlo)
+    roots = {}
+    for comp in re.split(r"\n(?=\S)", hlo):
+        root = re.search(r"ROOT \S+ = \S+ ([\w-]+)\(", comp)
+        if root:
+            roots[comp.split(" ", 1)[0]] = root.group(1)
+    ops = []
+    for op, rest in made:
+        if op == "fusion":
+            op = roots[re.search(r"calls=(%[\w.-]+)", rest).group(1)]
+        assert op in ("parameter", "get-tuple-element", "tuple", "copy",
+                      "scatter"), op
+        ops.append(op)
+    assert "scatter" in ops
+    assert ops.count("copy") == (0 if donated else len(leaves))
+    head = hlo.split("entry_computation_layout")[0]
+    aliased = {int(i) for i in
+               re.findall(r"\((\d+), \{\}, may-alias", head)}
+    first = len(jax.tree.leaves(params))
+    assert aliased == (set(range(first, first + len(leaves)))
+                       if donated else set())
+    expanded = (b, s, cfg.n_heads, cfg.head_dim)
+    for text, sep in ((lowered.as_text(), "x"), (hlo, ",")):
+        assert sep.join(map(str, expanded)) not in text
 
 
 def test_windowed_engine_matches_full_early():
